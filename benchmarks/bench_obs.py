@@ -1,4 +1,4 @@
-"""Observability benchmark: tracing overhead + determinism + cost crosscheck
+"""Observability benchmark: tracing overhead + determinism
 (EXPERIMENTS.md §Observability).
 
 Serves the same 8-request wave (2 tenants, the standard
@@ -14,13 +14,7 @@ drift hits both equally.  The gates:
   * tracing ON costs ≤ 5% wall-clock (min-of-reps, interleaved);
   * the span-tree summary is byte-identical across two fresh seeded runs
     (no wall-clock leaks into it — CI can require exact equality);
-  * the captured trace is valid Chrome/Perfetto trace-event JSON;
-  * the cost-model crosscheck (predicted vs observed kernel launches per
-    op family) reproduces its documented deviations exactly.  The serve
-    path dispatches ZERO Pallas NTT kernels (repro.core.ntt is pure jnp;
-    the Pallas NTT runs only via kernels.ntt.ops), so the ntt family sits
-    at a deterministic −100% — gated numerically so it cannot drift
-    silently.
+  * the captured trace is valid Chrome/Perfetto trace-event JSON.
 
     PYTHONPATH=src python -m benchmarks.bench_obs [--quick] [--out PATH]
 """
@@ -104,7 +98,7 @@ def _perfetto_valid(doc) -> bool:
 
 def _traced_run(N: int, L: int, seed: int):
     """Fresh engine, warmed, then one wave captured with spans + OpTrace.
-    Returns (span_summary, crosscheck, perfetto_doc, launches)."""
+    Returns (span_summary, perfetto_doc, launches)."""
     p, store = _setup(N, L)
     eng = FheServeEngine(store, max_batch=WAVE)
     _submit_wave(eng, p, store, 0)
@@ -113,8 +107,7 @@ def _traced_run(N: int, L: int, seed: int):
         with he_trace.trace_ops() as op_trace:
             _, launches, _, _ = _timed_wave(eng, p, store, seed)
     assert dict(op_trace.launches) == launches    # satellite-3 parity
-    xc = tracing.cost_crosscheck(op_trace)
-    return tr.span_summary(), xc, tr.to_perfetto(), launches
+    return tr.span_summary(), tr.to_perfetto(), launches
 
 
 def run(reps: int, N: int, L: int) -> dict:
@@ -152,9 +145,9 @@ def run(reps: int, N: int, L: int) -> dict:
 
     overhead_pct = 100.0 * (min(times_on) / min(times_off) - 1.0)
 
-    # determinism + crosscheck on two fully fresh runs with the same seeds
-    summ_a, xc, doc, traced_launches = _traced_run(N, L, 2000)
-    summ_b, _, _, _ = _traced_run(N, L, 2000)
+    # determinism on two fully fresh runs with the same seeds
+    summ_a, doc, traced_launches = _traced_run(N, L, 2000)
+    summ_b, _, _ = _traced_run(N, L, 2000)
     deterministic = summ_a == summ_b
     perfetto_ok = (_perfetto_valid(doc)
                    and json.loads(json.dumps(doc)) == doc)
@@ -164,8 +157,6 @@ def run(reps: int, N: int, L: int) -> dict:
         f.seek(0)
         perfetto_ok &= _perfetto_valid(json.load(f))
 
-    devs = {fam: abs(d["deviation_pct"])
-            for fam, d in xc["families"].items()}
     from benchmarks.bench_env import gate_env, run_env
     out = {
         "bench": "obs",
@@ -179,7 +170,6 @@ def run(reps: int, N: int, L: int) -> dict:
         "launches_on": launches_on,
         "traced_launches": traced_launches,
         "span_summary": summ_a,
-        "crosscheck": xc,
         "gate": {
             # booleans: invariants; numbers: must not grow vs baseline;
             # strings (mode/backend): must equal the baseline's
@@ -196,10 +186,6 @@ def run(reps: int, N: int, L: int) -> dict:
             "traced_wave_spans": sum(v["count"]
                                      for v in summ_a["spans"].values()),
             "traced_wave_launches": sum(traced_launches.values()),
-            "crosscheck_abs_dev_ntt": devs["ntt"],
-            "crosscheck_abs_dev_bconv": devs["bconv"],
-            "crosscheck_abs_dev_auto": devs["auto"],
-            "crosscheck_abs_dev_eltwise": devs["eltwise"],
         },
     }
     return out

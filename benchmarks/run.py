@@ -74,7 +74,7 @@ def main() -> None:
                              ["--quick", "--out",
                               _tmp("BENCH_recovery.json")])))
         from benchmarks import bench_obs
-        sections.append(("Observability: tracing overhead + crosscheck",
+        sections.append(("Observability: tracing overhead + determinism",
                          lambda: bench_obs.main(
                              ["--quick", "--out", _tmp("BENCH_obs.json")])))
         from benchmarks import bench_distributed

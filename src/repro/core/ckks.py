@@ -22,6 +22,7 @@ from . import bconv as bc
 from . import const_cache
 from . import guards
 from . import poly as pl
+from . import spans
 from . import trace
 from .keys import Ciphertext, EvalKey, KeySet
 from .params import CkksParams
@@ -105,38 +106,43 @@ def mod_up_all_digits(d: pl.RnsPoly, params: CkksParams) -> list[pl.RnsPoly]:
     The digit's own limbs reuse the original NTT-domain data (no re-NTT of
     copied limbs) — only BConv outputs pay forward transforms.
     """
-    ell = d.ell
-    d_ntt = d.to_ntt()
-    d_coeff = d.to_coeff()
-    full_q = params.q[:ell]
-    exts = []
-    start = 0
-    for dj in params.digit_bases(ell):
-        sl = slice(start, start + len(dj))
-        digit = pl.RnsPoly(d_coeff.data[..., sl, :], dj, pl.COEFF)
-        digit_ntt = pl.RnsPoly(d_ntt.data[..., sl, :], dj, pl.NTT)
-        exts.append(bc.mod_up_digit(digit, full_q, params.p, digit_ntt))
-        start += len(dj)
-    return exts
+    with spans.span("modup"):
+        ell = d.ell
+        d_ntt = d.to_ntt()
+        d_coeff = d.to_coeff()
+        full_q = params.q[:ell]
+        exts = []
+        start = 0
+        for dj in params.digit_bases(ell):
+            sl = slice(start, start + len(dj))
+            digit = pl.RnsPoly(d_coeff.data[..., sl, :], dj, pl.COEFF)
+            digit_ntt = pl.RnsPoly(d_ntt.data[..., sl, :], dj, pl.NTT)
+            exts.append(bc.mod_up_digit(digit, full_q, params.p, digit_ntt))
+            start += len(dj)
+        return exts
 
 
 def ks_inner(exts: list[pl.RnsPoly], evk: EvalKey, params: CkksParams,
              ell: int) -> tuple[pl.RnsPoly, pl.RnsPoly]:
     """Σ_j ext_j ⊙ evk_j over Q_ℓ∪P, then ModDown by P.  Returns (ka, kb)."""
-    pairs = _evk_at_level(evk, params, ell)
-    # PRNG evk (§V-B): only the b halves hit memory; a is re-expanded on-chip.
-    trace.record("evk_load_bytes", 1,
-                 len(pairs) * (ell + params.K) * params.N * 4)
-    trace.record_he("KS")
-    acc_a = acc_b = None
-    for ext, (aj, bj) in zip(exts, pairs):
-        ta, tb = ext * aj, ext * bj
-        acc_a = ta if acc_a is None else acc_a + ta
-        acc_b = tb if acc_b is None else acc_b + tb
-    # both components stacked on a leading axis → ONE ModDown (iNTT, BConv
-    # kernel grid, NTT, P⁻¹ scale all batched over the pair)
-    acc = pl.RnsPoly(jnp.stack([acc_a.data, acc_b.data]), acc_a.basis, pl.NTT)
-    k = bc.mod_down(acc, params.q[:ell], params.p)
+    with spans.span("inner"):
+        pairs = _evk_at_level(evk, params, ell)
+        # PRNG evk (§V-B): only the b halves hit memory; a is re-expanded
+        # on-chip.
+        trace.record("evk_load_bytes", 1,
+                     len(pairs) * (ell + params.K) * params.N * 4)
+        trace.record_he("KS")
+        acc_a = acc_b = None
+        for ext, (aj, bj) in zip(exts, pairs):
+            ta, tb = ext * aj, ext * bj
+            acc_a = ta if acc_a is None else acc_a + ta
+            acc_b = tb if acc_b is None else acc_b + tb
+        # both components stacked on a leading axis → ONE ModDown (iNTT,
+        # BConv kernel grid, NTT, P⁻¹ scale all batched over the pair)
+        acc = pl.RnsPoly(jnp.stack([acc_a.data, acc_b.data]), acc_a.basis,
+                         pl.NTT)
+    with spans.span("moddown"):
+        k = bc.mod_down(acc, params.q[:ell], params.p)
     ka = pl.RnsPoly(k.data[0], k.basis, k.domain)
     kb = pl.RnsPoly(k.data[1], k.basis, k.domain)
     return ka, kb
@@ -407,21 +413,23 @@ def _fused_galois_ks(exts: list[pl.RnsPoly], gelts: tuple[int, ...],
     ext_basis = exts[0].basis
     N = exts[0].N
     J, L, R = len(exts), len(ext_basis), len(gelts)
-    stack = jnp.stack([e.data if e.data.ndim == 3 else e.data[None]
-                       for e in exts])                      # (J, G, L, N)
-    idx = tuple(range(ell)) + tuple(params.L + k for k in range(params.K))
-    ndig = len(params.digit_bases(ell))
-    evk_a, evk_b = keys.galois_stacked(gelts, idx, ext_basis, ndig)
-    trace.record("auto", L, N, J * R)            # digit permutations
-    trace.record("elt_mul", L, N, 2 * J * R)     # evk MAC products
-    for _ in gelts:
-        trace.record("evk_load_bytes", 1, J * L * N * 4)
-        trace.record_he("KS")
-    acc = auto_ops.auto_ks(stack, evk_a, evk_b, N, gelts, ext_basis)
+    with spans.span("auto_ks", rotations=R):
+        stack = jnp.stack([e.data if e.data.ndim == 3 else e.data[None]
+                           for e in exts])                  # (J, G, L, N)
+        idx = tuple(range(ell)) + tuple(params.L + k for k in range(params.K))
+        ndig = len(params.digit_bases(ell))
+        evk_a, evk_b = keys.galois_stacked(gelts, idx, ext_basis, ndig)
+        trace.record("auto", L, N, J * R)            # digit permutations
+        trace.record("elt_mul", L, N, 2 * J * R)     # evk MAC products
+        for _ in gelts:
+            trace.record("evk_load_bytes", 1, J * L * N * 4)
+            trace.record_he("KS")
+        acc = auto_ops.auto_ks(stack, evk_a, evk_b, N, gelts, ext_basis)
     # ONE ModDown for the whole set: every (rotation, component) pair rides
     # the leading axes through the iNTT/BConv-kernel/NTT/P⁻¹ chain.
-    return bc.mod_down(pl.RnsPoly(acc, ext_basis, pl.NTT),
-                       params.q[:ell], params.p)
+    with spans.span("moddown"):
+        return bc.mod_down(pl.RnsPoly(acc, ext_basis, pl.NTT),
+                           params.q[:ell], params.p)
 
 
 def _rotated_b(b: pl.RnsPoly, gelts: tuple[int, ...]) -> pl.RnsPoly:
@@ -755,21 +763,22 @@ def _rescale_qinv(basis: tuple[int, ...]) -> np.ndarray:
 
 
 def _rescale_once(a: pl.RnsPoly, b: pl.RnsPoly, scale: float):
-    basis = a.basis
-    ql = basis[-1]
-    new_basis = basis[:-1]
-    qinv = _rescale_qinv(basis)
-    # both ciphertext components ride one leading axis: the top-limb iNTT,
-    # the vectorized centered lift, the re-NTT, and the q_ℓ⁻¹ scale each
-    # dispatch once for the pair.
-    xn = jnp.stack([a.to_ntt().data, b.to_ntt().data])
-    last = pl.RnsPoly(xn[..., -1:, :], (ql,), pl.NTT).to_coeff()
-    lifted = bc.centered_lift_single(last.data[..., 0, :], ql, new_basis)
-    lifted_ntt = pl.RnsPoly(lifted, new_basis, pl.COEFF).to_ntt()
-    head = pl.RnsPoly(xn[..., :-1, :], new_basis, pl.NTT)
-    out = (head - lifted_ntt).mul_scalar(qinv)
-    return (pl.RnsPoly(out.data[0], new_basis, pl.NTT),
-            pl.RnsPoly(out.data[1], new_basis, pl.NTT), scale / ql)
+    with spans.span("rescale"):
+        basis = a.basis
+        ql = basis[-1]
+        new_basis = basis[:-1]
+        qinv = _rescale_qinv(basis)
+        # both ciphertext components ride one leading axis: the top-limb
+        # iNTT, the vectorized centered lift, the re-NTT, and the q_ℓ⁻¹
+        # scale each dispatch once for the pair.
+        xn = jnp.stack([a.to_ntt().data, b.to_ntt().data])
+        last = pl.RnsPoly(xn[..., -1:, :], (ql,), pl.NTT).to_coeff()
+        lifted = bc.centered_lift_single(last.data[..., 0, :], ql, new_basis)
+        lifted_ntt = pl.RnsPoly(lifted, new_basis, pl.COEFF).to_ntt()
+        head = pl.RnsPoly(xn[..., :-1, :], new_basis, pl.NTT)
+        out = (head - lifted_ntt).mul_scalar(qinv)
+        return (pl.RnsPoly(out.data[0], new_basis, pl.NTT),
+                pl.RnsPoly(out.data[1], new_basis, pl.NTT), scale / ql)
 
 
 def level_drop(ct: Ciphertext, ell: int) -> Ciphertext:

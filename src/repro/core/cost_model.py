@@ -252,42 +252,6 @@ def predict_collectives(op: str, cm: ClusterMap, *, n_in: int = 0,
     raise ValueError(f"unknown primitive {op!r}")
 
 
-def predict_launches(trace: OpTrace) -> dict:
-    """First-order kernel-dispatch prediction per family from primitive
-    records — the analytic half of the observability crosscheck
-    (``repro.runtime.tracing.cost_crosscheck``).
-
-    The fused jax_pallas engine batches all leading dims into one grid, so
-    to first order every primitive *record event* corresponds to one kernel
-    dispatch of its family:
-
-      * ``ntt``      — one batched transform per ``ntt``/``intt`` record
-        (``poly.to_ntt``/``to_coeff`` record once, then dispatch once);
-      * ``bconv``    — one BConvU grid per ``bconv_mul`` record (the eager
-        BConv engine records identically but dispatches zero kernels —
-        a deliberate, visible deviation under ``REPRO_BCONV_ENGINE=eager``);
-      * ``auto``     — one AutoU / fused AutoU∘KS launch per ``auto``
-        record (compared against observed ``automorphism + auto_ks``);
-      * ``eltwise``  — one fused EFU launch per ``elt_mul`` record.  The
-        fused tensor product folds 4 recorded products into 2 launches and
-        pure-jnp element-wise adds dispatch none, so real workloads observe
-        FEWER eltwise launches than predicted — the deviation the bench
-        documents and bounds.
-
-    Deviations between this prediction and the observed
-    ``kernels/config.launch_counts()`` deltas are exactly the fusion /
-    batching effects the paper's primitive-function accounting abstracts
-    away; ``BENCH_obs.json`` gates that they stay put.
-    """
-    calls = trace.calls
-    return {
-        "ntt": calls.get("ntt", 0) + calls.get("intt", 0),
-        "bconv": calls.get("bconv_mul", 0),
-        "auto": calls.get("auto", 0),
-        "eltwise": calls.get("elt_mul", 0),
-    }
-
-
 def estimate(trace: OpTrace, pkg: PackageConfig,
              limb_dup: str = "auto") -> CostBreakdown:
     cm = pkg.cm

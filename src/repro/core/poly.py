@@ -24,6 +24,7 @@ from . import guards
 from . import modmath as mm
 from . import ntt as nttm
 from . import rns
+from . import spans
 from . import trace
 
 COEFF = "coeff"
@@ -76,7 +77,8 @@ class RnsPoly:
         if ctx is not None:
             return RnsPoly(dist.sharded_ntt(ctx, self.data, self.basis, True),
                            self.basis, NTT)
-        return RnsPoly(_ntt(self.data, self.c()), self.basis, NTT)
+        with spans.span("ntt"):
+            return RnsPoly(_ntt(self.data, self.c()), self.basis, NTT)
 
     def to_coeff(self) -> "RnsPoly":
         if self.domain == COEFF:
@@ -87,7 +89,8 @@ class RnsPoly:
         if ctx is not None:
             return RnsPoly(dist.sharded_ntt(ctx, self.data, self.basis, False),
                            self.basis, COEFF)
-        return RnsPoly(_intt(self.data, self.c()), self.basis, COEFF)
+        with spans.span("intt"):
+            return RnsPoly(_intt(self.data, self.c()), self.basis, COEFF)
 
     # -- ring ops (domain-agnostic element-wise; mul requires NTT) -----------
     def _check_aligned(self, o: "RnsPoly", op: str) -> None:

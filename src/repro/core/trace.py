@@ -27,12 +27,10 @@ class OpTrace:
     # kernel-grain mirror: per-family Pallas dispatch counts, fed by
     # repro.kernels.config.count_launch so an active trace sees EXACTLY the
     # launches kernels/config tallies on the same workload (the fused/batched
-    # paths dispatch far fewer kernels than the primitive records suggest —
-    # this is the ground truth the cost-model crosscheck reconciles against)
+    # paths dispatch far fewer kernels than the primitive records suggest)
     launches: collections.Counter = dataclasses.field(
         default_factory=collections.Counter)
-    # record-call events per func (no limb/coeff weighting): the unit
-    # cost_model.predict_launches maps to expected kernel dispatches
+    # record-call events per func (no limb/coeff weighting)
     calls: collections.Counter = dataclasses.field(
         default_factory=collections.Counter)
 

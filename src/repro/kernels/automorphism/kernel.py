@@ -120,4 +120,5 @@ def auto_ks_pallas(exts, evk_a, evk_b, perms, q, qinv_neg, r2, mu_hi, mu_lo,
         out_specs=pl.BlockSpec((1, 2, Lb, tile), lambda r, l, c: (r, 0, l, c)),
         out_shape=jax.ShapeDtypeStruct((R, 2, L, N), jnp.uint32),
         interpret=interpret,
+        name="auto_ks_mac",
     )(rot, evk_a, evk_b, q, qinv_neg, r2, mu_hi, mu_lo)

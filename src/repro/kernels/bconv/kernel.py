@@ -91,5 +91,6 @@ def bconv_matmul_pallas(t, table, table_shoup, q_dst, mu_hi, mu_lo,
         out_specs=pl.BlockSpec((bb, kb, tile), lambda b, j, c: (b, j, c)),
         out_shape=jax.ShapeDtypeStruct((B, K, N), jnp.uint32),
         interpret=interpret,
+        name="bconv_matmul",
     )(t, cols(table), cols(table_shoup), q_dst, mu_hi, mu_lo)
     return out[0] if squeeze else out

@@ -79,5 +79,6 @@ def eltwise_pallas(op: str, q, qinv_neg, r2, *arrays,
         out_specs=arr_spec,
         out_shape=jax.ShapeDtypeStruct((P, ell, N), jnp.uint32),
         interpret=interpret,
+        name=f"eltwise_{op}",
     )(q, qinv_neg, r2, *flat)
     return out.reshape(shape)

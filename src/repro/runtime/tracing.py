@@ -12,7 +12,9 @@ enclosing span through the existing hook points
 
 Zero overhead when off is a hard contract:
 
-* no tracer active → :func:`span` returns one shared no-op context manager,
+* no tracer active → :func:`span` (:func:`repro.core.spans.span`, which
+  the call sites of every layer import) returns one shared no-op context
+  manager,
   :func:`event`/:func:`annotate` are a single ``is None`` test, and **no
   hook is installed anywhere** — the kernel hot path is bit-identical to a
   build that never imported this module;
@@ -35,23 +37,32 @@ Exports per captured run:
   per-family launch / upload / fault attribution per span path, no
   wall-clock) that CI gates exactly across seeded runs;
 * :func:`metrics_snapshot` / :func:`render_prometheus` — counters +
-  p50/p95/p99 histograms as JSON or Prometheus exposition text;
-* :func:`cost_crosscheck` — reconcile observed per-family kernel launches
-  against :func:`repro.core.cost_model.predict_launches` on the same
-  :class:`~repro.core.trace.OpTrace`, reporting predicted-vs-observed
-  deviation per op family (gated by ``BENCH_obs.json``).
+  p50/p95/p99 histograms as JSON or Prometheus exposition text.
+
+**Profiler sink.** ``start(profiler=True)`` (or ``capture(profiler=True)``)
+makes every span also open a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` (attributes become the event's stats), so a running
+``jax.profiler`` trace holds the engine's spans on the same clock as the
+device's programs: a device idle gap can be blamed on the engine phase the
+host was in, and a program on the span that launched it.  Without a
+``tracer`` the sink is all that is switched on — no hook, no in-memory
+record, one TraceMe per span.  The in-memory :class:`Tracer` keeps its own
+``perf_counter`` clock; only profiler-sink spans share the device clock.
 """
 from __future__ import annotations
 
 import collections
 import contextvars
+import functools
 import json
 import math
 import os
 import threading
 import time
 
-from repro.core import const_cache
+from jax.profiler import TraceAnnotation
+
+from repro.core import const_cache, spans
 from repro.kernels import config as kconfig
 
 # ----------------------------------------------------------------------------
@@ -201,20 +212,6 @@ class Span:
         self.marks = collections.Counter()      # annotate() tallies
 
 
-class _NullSpan:
-    """The shared do-nothing span returned while tracing is off."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
 _current: contextvars.ContextVar[Span | None] = contextvars.ContextVar(
     "repro_trace_span", default=None)
 
@@ -245,13 +242,41 @@ class _SpanCtx:
         return False
 
 
+class _ProfiledSpanCtx(_SpanCtx):
+    """A :class:`_SpanCtx` that also opens the span's profiler annotation."""
+
+    __slots__ = ("_ann",)
+
+    def __enter__(self) -> Span:
+        self._ann = _annotation(self._name, self._attrs)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+SPAN_PREFIX = "repro."          # profiler event name = prefix + span name
+
+
+def _annotation(name: str, attrs: dict) -> TraceAnnotation:
+    return TraceAnnotation(SPAN_PREFIX + name, **attrs)
+
+
 # ----------------------------------------------------------------------------
 # Tracer
 # ----------------------------------------------------------------------------
 
 
 class Tracer:
-    """One capture: spans, instant events, request lifecycle, hook tallies."""
+    """One capture: spans, instant events, request lifecycle, hook tallies.
+
+    Timestamps are on the tracer's own clock (``perf_counter`` from its
+    start), which the Perfetto export uses; they do not line up with a
+    ``jax.profiler`` trace — only profiler-sink spans share the device
+    clock (module docstring)."""
 
     def __init__(self, clock=time.perf_counter):
         self._clock = clock
@@ -464,36 +489,54 @@ def _uninstall_hooks(tracer: Tracer) -> None:
     _prev_launch = _prev_stage = None
 
 
-def start(tracer: Tracer | None = None) -> Tracer:
-    """Activate tracing process-wide (installs the chained hooks)."""
+def start(tracer: Tracer | None = None, *,
+          profiler: bool = False) -> Tracer | None:
+    """Activate tracing process-wide; returns the in-memory tracer.
+
+    By default that is ``tracer`` (or a new :class:`Tracer`), with the
+    chained hooks installed.  ``profiler=True`` adds the profiler sink
+    (module docstring); with no ``tracer`` given it is the only sink — no
+    hook is installed, nothing is recorded in memory, and None is
+    returned."""
     global _active
-    if _active is not None:
+    if spans.opener() is not None:
         raise RuntimeError("a tracer is already active")
+    if profiler and tracer is None:
+        spans.set_opener(_annotation)
+        return None
     _active = tracer if tracer is not None else Tracer()
     _install_hooks(_active)
+    spans.set_opener(functools.partial(
+        _ProfiledSpanCtx if profiler else _SpanCtx, tracer=_active))
     return _active
 
 
-def stop() -> Tracer:
-    """Deactivate tracing; returns the captured tracer.  Hot paths are
-    hook-free again the moment this returns."""
+def stop() -> Tracer | None:
+    """Deactivate tracing; returns the captured tracer (None for the
+    profiler sink alone).  Hot paths are hook-free again the moment this
+    returns."""
     global _active
-    if _active is None:
+    if spans.opener() is None:
         raise RuntimeError("no tracer active")
     t = _active
     _active = None
-    _uninstall_hooks(t)
+    spans.set_opener(None)
+    if t is not None:
+        _uninstall_hooks(t)
     return t
 
 
 class capture:
-    """``with tracing.capture() as tr:`` — start/stop as a context manager."""
+    """``with tracing.capture() as tr:`` — start/stop as a context manager
+    (``capture(profiler=True)``: the profiler sink alone, ``tr`` is None)."""
 
-    def __init__(self, tracer: Tracer | None = None):
+    def __init__(self, tracer: Tracer | None = None, *,
+                 profiler: bool = False):
         self._tracer = tracer
+        self._profiler = profiler
 
-    def __enter__(self) -> Tracer:
-        self.tracer = start(self._tracer)
+    def __enter__(self) -> Tracer | None:
+        self.tracer = start(self._tracer, profiler=self._profiler)
         return self.tracer
 
     def __exit__(self, *exc):
@@ -501,12 +544,7 @@ class capture:
         return False
 
 
-def span(name: str, **attrs):
-    """Open a nestable span (no-op shared object when tracing is off)."""
-    t = _active
-    if t is None:
-        return _NULL_SPAN
-    return _SpanCtx(name, attrs, t)
+span = spans.span      # the call sites' entry point (repro.core.spans)
 
 
 def annotate(key: str, n: int = 1) -> None:
@@ -538,7 +576,7 @@ def request_event(kind: str, rid: int, **attrs) -> None:
 
 
 # ----------------------------------------------------------------------------
-# Metrics snapshot (Prometheus-style) + cost-model crosscheck
+# Metrics snapshot (Prometheus-style)
 # ----------------------------------------------------------------------------
 
 
@@ -599,46 +637,6 @@ def render_prometheus(snap: dict, prefix: str = "repro") -> str:
         emit(f"{base}_count", h["count"])
         emit(f"{base}_sum", h["mean"] * h["count"])
     return "\n".join(lines) + "\n"
-
-
-def cost_crosscheck(op_trace, observed: dict | None = None,
-                    n_cores: int = 16) -> dict:
-    """Reconcile observed kernel launches against the analytic prediction.
-
-    ``op_trace`` is an :class:`~repro.core.trace.OpTrace` captured over the
-    workload; ``observed`` is a per-family launch-count dict (defaults to
-    the trace's own kernel-grain mirror, which equals the
-    ``kernels/config`` region deltas by construction).  Returns per-family
-    ``{predicted, observed, deviation_pct}`` plus the
-    :func:`repro.core.cost_model.estimate` time breakdown for the paper's
-    primitive-function accounting.
-    """
-    from repro.core import cost_model
-    predicted = cost_model.predict_launches(op_trace)
-    if observed is None:
-        observed = dict(op_trace.launches)
-    merged = {
-        "ntt": observed.get("ntt", 0),
-        "bconv": observed.get("bconv", 0),
-        "auto": observed.get("automorphism", 0) + observed.get("auto_ks", 0),
-        "eltwise": observed.get("eltwise", 0),
-    }
-    families = {}
-    for fam in sorted(predicted):
-        p, o = predicted[fam], merged.get(fam, 0)
-        if p:
-            dev = round(100.0 * (o - p) / p, 3)
-        else:
-            dev = 0.0 if not o else float("inf")
-        families[fam] = {"predicted": p, "observed": o,
-                         "deviation_pct": dev}
-    est = cost_model.estimate(op_trace, cost_model.default_package(n_cores))
-    return {
-        "families": families,
-        "observed_raw": dict(sorted(observed.items())),
-        "model_seconds": {"t_compute": est.t_compute, "t_nop": est.t_nop,
-                          "t_hbm": est.t_hbm, "t_total": est.t_total},
-    }
 
 
 # ----------------------------------------------------------------------------

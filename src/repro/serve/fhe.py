@@ -373,9 +373,12 @@ class FheServeEngine:
         self.keystore.begin_step()
         now = self._clock()
         if self.enforce_deadlines:
-            self._expire_active(now)
-        self._shed(now)
-        self._fill_slots(now)
+            with tracing.span("expire"):
+                self._expire_active(now)
+        with tracing.span("shed"):
+            self._shed(now)
+        with tracing.span("fill"):
+            self._fill_slots(now)
         if not self.active:
             self.overload.end_step()
             self._update_health()
@@ -389,24 +392,26 @@ class FheServeEngine:
             dead = {req.rid for req, _, _ in fs}
             survivors = [it for it in group if it[0].rid not in dead]
             if survivors:
-                failures.extend(self._inject_and_check_outputs(survivors))
-        failed_by_rid = {req.rid: (status, reason)
-                         for req, status, reason in failures}
-        still = []
-        now = self._clock()
-        for req in self.active:
-            if req.rid in failed_by_rid:
-                status, reason = failed_by_rid[req.rid]
-                self._fail(req, status, reason, now)
-                continue
-            req.pc += 1
-            if req.pc >= len(req.program):
-                self._finish(req, now)
-            else:
-                still.append(req)
-        self.active = still
-        self.overload.end_step()
-        self._update_health()
+                with tracing.span("outputs"):
+                    failures.extend(self._inject_and_check_outputs(survivors))
+        with tracing.span("retire"):
+            failed_by_rid = {req.rid: (status, reason)
+                             for req, status, reason in failures}
+            still = []
+            now = self._clock()
+            for req in self.active:
+                if req.rid in failed_by_rid:
+                    status, reason = failed_by_rid[req.rid]
+                    self._fail(req, status, reason, now)
+                    continue
+                req.pc += 1
+                if req.pc >= len(req.program):
+                    self._finish(req, now)
+                else:
+                    still.append(req)
+            self.active = still
+            self.overload.end_step()
+            self._update_health()
         return len(ready)
 
     def _update_health(self) -> None:

@@ -83,6 +83,65 @@ def test_span_nesting_paths_and_restore():
     assert tr.launches == {"ntt": 1}
 
 
+def _profiled(fn, tmp_path):
+    """Run ``fn`` under a CPU ``jax.profiler`` trace; returns the host
+    events named ``repro.*`` as (name, start_ns, end_ns, stats)."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    pb = sorted(Path(tmp_path).rglob("*.xplane.pb"))[-1]
+    pd = jax.profiler.ProfileData.from_file(str(pb))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(tracing.SPAN_PREFIX)]
+
+
+def test_profiler_sink_writes_nested_spans(tmp_path):
+    def body():
+        with tracing.capture(profiler=True) as tr:
+            assert tr is None
+            # the sink alone installs no hook and records nothing in memory
+            assert kconfig.get_launch_hook() is None
+            assert const_cache.get_stage_hook() is None
+            assert faults.get_fire_hook() is None
+            assert tracing.active_tracer() is None
+            with pytest.raises(RuntimeError):
+                tracing.start()
+            with tracing.span("step"):
+                with tracing.span("dispatch.hrot", batch=2):
+                    kconfig.count_launch("auto_ks")
+    evs = _profiled(body, tmp_path)
+    assert [n for n, *_ in evs] == ["repro.step", "repro.dispatch.hrot"]
+    (_, s0, e0, _), (_, s1, e1, stats) = evs
+    assert s0 <= s1 and e1 <= e0
+    assert stats["batch"] == 2             # attributes stay off the name
+    # off again: the shared no-op, and no hook
+    assert tracing.span("x") is tracing.span("y")
+    assert kconfig.get_launch_hook() is None
+
+
+def test_profiler_sink_beside_an_in_memory_tracer(tmp_path):
+    got = {}
+
+    def body():
+        with tracing.capture(tracing.Tracer(), profiler=True) as tr:
+            assert kconfig.get_launch_hook() is not None
+            with tracing.span("a"):
+                with tracing.span("b"):
+                    kconfig.count_launch("ntt")
+        got["tr"] = tr
+    evs = _profiled(body, tmp_path)
+    assert [n for n, *_ in evs] == ["repro.a", "repro.b"]
+    tr = got["tr"]
+    assert [s.path for s in tr.spans] == [("a", "b"), ("a",)]
+    assert tr.span_summary()["spans"]["a/b"]["launches"] == {"ntt": 1}
+    assert kconfig.get_launch_hook() is None
+
+
 def test_span_summary_has_no_wallclock():
     with tracing.capture() as tr:
         with tracing.span("s"):
@@ -156,6 +215,25 @@ def test_env_knob_rejects_unknown_mode():
         env=env, capture_output=True, text=True)
     assert r.returncode != 0
     assert "REPRO_TRACE" in r.stderr
+
+
+def test_ring_and_ckks_layers_import_no_runtime():
+    """Span call sites below the runtime import only the leaf
+    ``repro.core.spans``; the runtime sets what a span opens."""
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    code = ("import sys, repro.core.poly, repro.core.ckks; "
+            "print(sorted(m for m in sys.modules "
+            "if m.startswith('repro.runtime')))")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == "[]"
+    from repro.core import spans
+    assert tracing.span is spans.span
+    with tracing.capture():
+        assert spans.opener() is not None
+    assert spans.opener() is None and spans.span("x") is spans.NULL_SPAN
 
 
 # ----------------------------------------------------------------------------
@@ -291,26 +369,6 @@ def test_metrics_snapshot_and_prometheus_rendering():
     assert "repro_serve_serve_seconds_count 1" in text
 
 
-def test_cost_crosscheck_families():
-    t = he_trace.OpTrace()
-    t.add("ntt", 4, N)                    # predicted ntt: 2 (one ntt+intt)
-    t.add("intt", 4, N)
-    t.add("bconv_mul", 4, N)
-    t.add("elt_mul", 4, N)
-    t.add_launch("bconv", 1)              # observed: bconv exact...
-    t.add_launch("eltwise", 2)            # ...eltwise over by 1
-    xc = tracing.cost_crosscheck(t)
-    fam = xc["families"]
-    assert fam["ntt"] == {"predicted": 2, "observed": 0,
-                          "deviation_pct": -100.0}
-    assert fam["bconv"]["deviation_pct"] == 0.0
-    assert fam["eltwise"] == {"predicted": 1, "observed": 2,
-                              "deviation_pct": 100.0}
-    assert fam["auto"] == {"predicted": 0, "observed": 0,
-                           "deviation_pct": 0.0}
-    assert xc["model_seconds"]["t_total"] > 0.0
-
-
 # ----------------------------------------------------------------------------
 # serve integration: traced waves (module fixture shares compiled shapes)
 # ----------------------------------------------------------------------------
@@ -367,6 +425,39 @@ def test_traced_wave_timeline_and_determinism(serve_setup):
         eng.run_until_drained()
     assert json.dumps(summ, sort_keys=True) == \
         json.dumps(tr2.span_summary(), sort_keys=True)
+
+
+def test_engine_spans_under_the_profiler_sink(serve_setup, tmp_path):
+    p, store, eng = serve_setup
+
+    def body():
+        with tracing.capture(profiler=True):
+            _wave(eng, p, store, 300)
+            eng.run_until_drained()
+    evs = _profiled(body, tmp_path)
+    by = {}
+    for n, s, e, _ in evs:
+        by.setdefault(n[len(tracing.SPAN_PREFIX):], []).append((s, e))
+
+    def inside(name, outer):
+        return by[name] and all(any(a <= s and e <= b for o in outer
+                                    for a, b in by.get(o, ()))
+                                for s, e in by[name])
+    kinds = [n for n in by if n.startswith("dispatch.")]
+    assert {"dispatch.hmult", "dispatch.rescale", "dispatch.hrot",
+            "dispatch.hadd"} <= set(kinds)
+    for name in kinds + ["fill", "retire", "outputs"]:
+        assert inside(name, ["step"]), name
+    assert inside("stage", ["fill"])
+    for name in ("plan", "scatter", "modup", "moddown", "rescale",
+                 "auto_ks"):
+        assert inside(name, kinds), name
+    # encryption of the wave's inputs transforms outside any step; inside
+    # one, every transform belongs to a dispatch
+    by["ntt"] = [(s, e) for s, e in by["ntt"]
+                 if any(a <= s and e <= b for a, b in by["step"])]
+    assert inside("ntt", ["modup", "moddown", "rescale"] + kinds)
+    assert inside("inner", ["dispatch.hmult"])
 
 
 def test_tracing_off_is_bit_exact(serve_setup):
