@@ -3,7 +3,8 @@
 The CKKS layer runs the pure-``jnp`` path eagerly (un-jitted), so every
 ``jnp.asarray(numpy_table)`` inside a transform used to stage the table to the
 device again on *every call*.  This module stages each constant set exactly
-once per key — ``(basis, N)`` for :class:`~repro.core.ntt.NttConsts`,
+once per key — ``(basis, N)`` for :class:`~repro.core.ntt.NttConsts` and
+:class:`LimbConsts`,
 ``(basis, N, R)`` for :class:`~repro.core.ntt.FourStepConsts`, and an explicit
 key for ad-hoc scalar vectors — and hands back the same jax-array pytree on
 every subsequent lookup.  Under ``jit`` the arrays are already committed
@@ -63,7 +64,9 @@ def clear() -> None:
     store and the lru-cached device NttConsts/FourStepConsts alike."""
     _cache.clear()
     device_ntt_consts.cache_clear()
+    device_limb_consts.cache_clear()
     device_four_step_consts.cache_clear()
+    device_lane_consts.cache_clear()
     device_bconv_consts.cache_clear()
 
 
@@ -135,6 +138,27 @@ def device_ntt_consts(basis: tuple[int, ...], N: int) -> nttm.NttConsts:
     return nttm.NttConsts(*(_stage(f) for f in c))
 
 
+class LimbConsts(NamedTuple):
+    """The per-limb scalar columns of :class:`~repro.core.ntt.NttConsts`,
+    each (ℓ, 1): all that element-wise ring ops and the eltwise / auto_ks
+    kernels read, without the (ℓ, N) ψ tables no production transform
+    reads."""
+    q: Any
+    qinv_neg: Any
+    r2: Any
+    mu_hi: Any
+    mu_lo: Any
+
+
+@functools.lru_cache(maxsize=None)
+def device_limb_consts(basis: tuple[int, ...], N: int) -> LimbConsts:
+    """Per-limb scalar constants as device-resident jax arrays, staged once."""
+    tabs = [rns.prime_tables(q, N) for q in basis]
+    return LimbConsts(*(
+        _stage(np.array([[getattr(t, f)] for t in tabs], dtype=np.uint32))
+        for f in LimbConsts._fields))
+
+
 @functools.lru_cache(maxsize=None)
 def device_four_step_consts(basis: tuple[int, ...], N: int,
                             R: int) -> nttm.FourStepConsts:
@@ -145,6 +169,14 @@ def device_four_step_consts(basis: tuple[int, ...], N: int,
         col=col,
         **{name: _stage(getattr(fc, name))
            for name in fc._fields if name not in ("R", "C", "col")})
+
+
+@functools.lru_cache(maxsize=None)
+def device_lane_consts(basis: tuple[int, ...], N: int) -> nttm.LaneConsts:
+    """The lane-dense NTT's tables: the device-resident four-step tables of
+    :func:`~repro.core.ntt.lane_split`'s split, staged once."""
+    R, _ = nttm.lane_split(N)
+    return nttm.lane_consts(device_four_step_consts(basis, N, R))
 
 
 class BConvConsts(NamedTuple):

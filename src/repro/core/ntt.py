@@ -1,10 +1,15 @@
 """Negacyclic NTT — iterative reference + the recomposable four-step NTT (paper §III-B).
 
-Two implementations of the same transform:
+Three implementations of the same transform:
+
+* :func:`ntt_lanes` / :func:`intt_lanes` — the production transform of every
+  single-device ``RnsPoly.to_ntt``/``to_coeff`` (programs ``jit_ntt`` and
+  ``jit_intt``), at every N ≥ 4, on :func:`lane_split`'s R×C split
+  (256×256 at N = 2¹⁶): the four-step dataflow laid out for a TPU, every
+  butterfly along the rows, the 128-wide lane axis never split.
 
 * :func:`ntt` / :func:`intt` — fused iterative Cooley-Tukey / Gentleman-Sande
-  (Longa-Naehrig) with Shoup multipliers.  This is the *oracle* and the fast
-  pure-``jnp`` path used by the CKKS layer on CPU.
+  (Longa-Naehrig) with Shoup multipliers.  This is the *oracle*.
 
 * :func:`four_step_ntt` / :func:`four_step_intt` — the paper's recomposable
   dataflow: a length-N polynomial viewed as an R×C matrix; an R-point
@@ -47,6 +52,7 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -140,7 +146,7 @@ def bitrev_permute(x):
 
 
 # ----------------------------------------------------------------------------
-# Iterative fused CT / GS (the oracle and the CPU-fast path)
+# Iterative fused CT / GS (the oracle)
 # ----------------------------------------------------------------------------
 
 def _ntt_lazy(x, c: NttConsts):
@@ -461,6 +467,206 @@ def _cyclic_dft_eager(x, pow_tab, pow_tab_shoup, brev_c, q):
         x = x.reshape(*lead, C)
         m *= 2
     return x
+
+
+# ----------------------------------------------------------------------------
+# Lane-dense four-step NTT (the production single-device transform)
+# ----------------------------------------------------------------------------
+
+LANES = 128        # width of the TPU's lane axis (the last axis of an array)
+STAGE_GROUP = 4    # radix-2 butterfly stages computed per pass over the data
+
+
+def lane_split(N: int) -> tuple[int, int]:
+    """The R×C split of the lane-dense transform, for any N ≥ 4.
+
+    C is the power of two nearest √N from above, raised to :data:`LANES`
+    where that leaves R = N / C ≥ 2 rows, so that both phases keep whole
+    lane axes (256×256 at the paper's N = 2¹⁶, 2×128 at N = 2⁸; 8×16 at
+    N = 2⁷, where no split has C ≥ 128).
+    """
+    if N < 4:
+        raise ValueError(f"lane_split: N = {N} has no split with R, C >= 2")
+    C = 1 << -(-(N.bit_length() - 1) // 2)
+    if N >= 2 * LANES:
+        C = max(C, LANES)
+    return N // C, C
+
+
+class LaneConsts(NamedTuple):
+    """The :class:`FourStepConsts` tables the lane-dense transforms read.
+
+    Arrays only, so a jitted caller traces none of them as a shape: the
+    split is read back from ``twiddle.shape``.
+    """
+    q: np.ndarray                   # (ℓ, 1)
+    col_psi_rev: np.ndarray         # (ℓ, R) — R-point fused CT table, root ψ^C
+    col_psi_rev_shoup: np.ndarray
+    col_psi_inv_rev: np.ndarray     # (ℓ, R) — R-point fused GS table
+    col_psi_inv_rev_shoup: np.ndarray
+    r_inv: np.ndarray               # (ℓ, 1) — R⁻¹
+    r_inv_shoup: np.ndarray
+    twiddle: np.ndarray             # (ℓ, R, C) — ψ^{(2k₁+1)n₂}
+    twiddle_shoup: np.ndarray
+    twiddle_inv: np.ndarray
+    twiddle_inv_shoup: np.ndarray
+    row_stage: np.ndarray           # (ℓ, C-1) — stage-major ω powers, ω = ψ^{2R}
+    row_stage_shoup: np.ndarray
+    row_stage_inv: np.ndarray
+    row_stage_inv_shoup: np.ndarray
+    c_inv: np.ndarray               # (ℓ, 1) — C⁻¹
+    c_inv_shoup: np.ndarray
+
+
+def lane_consts(fc: FourStepConsts) -> LaneConsts:
+    """Pick the lane-dense transform's tables out of a four-step table set
+    (the same arrays: staging ``fc`` stages these)."""
+    return LaneConsts(
+        q=fc.q,
+        col_psi_rev=fc.col.psi_rev, col_psi_rev_shoup=fc.col.psi_rev_shoup,
+        col_psi_inv_rev=fc.col.psi_inv_rev,
+        col_psi_inv_rev_shoup=fc.col.psi_inv_rev_shoup,
+        r_inv=fc.col.n_inv, r_inv_shoup=fc.col.n_inv_shoup,
+        twiddle=fc.twiddle, twiddle_shoup=fc.twiddle_shoup,
+        twiddle_inv=fc.twiddle_inv, twiddle_inv_shoup=fc.twiddle_inv_shoup,
+        row_stage=fc.row_stage, row_stage_shoup=fc.row_stage_shoup,
+        row_stage_inv=fc.row_stage_inv,
+        row_stage_inv_shoup=fc.row_stage_inv_shoup,
+        c_inv=fc.c_inv, c_inv_shoup=fc.c_inv_shoup)
+
+
+def _bitrev_rows(x):
+    """Bit-reversal permutation of axis -2; the last (lane) axis stays whole."""
+    R = x.shape[-2]
+    k = R.bit_length() - 1
+    if k <= 1:
+        return x
+    lead = x.shape[:-2]
+    nl = len(lead)
+    y = x.reshape(*lead, *([2] * k), x.shape[-1])
+    perm = (*range(nl), *(nl + k - 1 - i for i in range(k)), nl + k)
+    return y.transpose(perm).reshape(x.shape)
+
+
+def _by_block(tab, m, R):
+    """Per-row twiddles of a stage with m blocks: row r reads tab[:, r·m // R]."""
+    return jnp.broadcast_to(tab[:, :, None], (tab.shape[0], m, R // m)
+                            ).reshape(-1, R)
+
+
+def _by_offset(tab, t, R):
+    """Per-row twiddles of a stage with half-size t: row r reads tab[:, r % t]."""
+    return jnp.broadcast_to(tab[:, None, :], (tab.shape[0], R // t, t)
+                            ).reshape(-1, R)
+
+
+# One butterfly on a pair of planes.  Jitted, so that a transform traces
+# each of them once per plane shape, not once per pair: the jitted caller
+# inlines them, and its trace and lowering (paid at every process start,
+# persistent compile cache or not) stay as short with 4 stages per pass as
+# with 1.
+@jax.jit
+def _ct(a, b, w, ws, q):
+    """Cooley-Tukey (a + w·b, a − w·b), lazy [0, 2q)."""
+    bw = mm.mulmod_shoup_lazy(b, w, ws, q)
+    return mm.addmod_lazy(a, bw, q + q), mm.submod_lazy(a, bw, q + q)
+
+
+@jax.jit
+def _gs(a, b, w, ws, q):
+    """Gentleman-Sande (a + b, (a − b)·w), lazy [0, 2q)."""
+    return (mm.addmod_lazy(a, b, q + q),
+            mm.mulmod_shoup_lazy(mm.submod_lazy(a, b, q + q), w, ws, q))
+
+
+def _row_butterflies(x, stages, q):
+    """Radix-2 butterfly stages along axis -2 of (..., ℓ, R, C), lazy [0, 2q).
+
+    ``stages`` lists ``(t, gs, w, ws)``: the partner distance t, the
+    butterfly (:func:`_gs` if ``gs``, else :func:`_ct`) and its (ℓ, R)
+    per-row twiddles, indexed by the row of the pair's first element.
+    :data:`STAGE_GROUP` consecutive stages run on one view of the rows,
+    (G, 2, …, 2, t_min), with one write at its end; the lane axis is never
+    split.
+    """
+    R, C = x.shape[-2:]
+    lead = x.shape[:-2]
+    qb = q[..., None, None]
+    for i in range(0, len(stages), STAGE_GROUP):
+        group = stages[i:i + STAGE_GROUP]
+        k = len(group)
+        t_hi = max(s[0] for s in group)
+        view = (R // (2 * t_hi), *([2] * k), t_hi >> (k - 1))
+        y = x.reshape(*lead, *view, C)
+        keys = list(np.ndindex(*([2] * k)))    # one bit per stage of the group
+        planes = {p: y[(..., slice(None), *p, slice(None), slice(None))]
+                  for p in keys}
+        for t, gs, w, ws in group:
+            axis = (t_hi // t).bit_length() - 1
+            w = w.reshape(-1, *view)
+            ws = ws.reshape(-1, *view)
+            for lo in keys:
+                if lo[axis]:
+                    continue
+                hi = (*lo[:axis], 1, *lo[axis + 1:])
+                at = (slice(None), slice(None), *lo, slice(None))
+                wp, wsp = w[at][..., None], ws[at][..., None]
+                planes[lo], planes[hi] = (_gs if gs else _ct)(
+                    planes[lo], planes[hi], wp, wsp, qb)
+        x = jnp.stack([planes[p] for p in keys], axis=-3).reshape(x.shape)
+    return x
+
+
+def ntt_lanes(x, lc: LaneConsts):
+    """Forward negacyclic NTT, lane-dense four-step; equal to :func:`ntt`.
+
+    A[n₁, n₂] = a[C·n₁ + n₂] keeps C on the lane axis while the R-point
+    negacyclic column NTT runs along the rows (fused CT, bit-reversed
+    output, put back in order by a row permutation); the inter-step twiddle
+    follows, then one transpose to (C, R), then the C-point cyclic DFT, again
+    along the rows (DIF, reordered by a row permutation).  Flattening the
+    (k₂, k₁) result gives â[k₁ + R·k₂]: natural order, no lane transpose.
+    """
+    R, C = lc.twiddle.shape[-2:]
+    lead = x.shape[:-1]
+    q3 = lc.q[..., None]
+    col = [(R // (2 * m), False, _by_block(lc.col_psi_rev[:, m:2 * m], m, R),
+            _by_block(lc.col_psi_rev_shoup[:, m:2 * m], m, R))
+           for m in (1 << i for i in range(R.bit_length() - 1))]
+    row = [(t, True, _by_offset(lc.row_stage[:, t - 1:2 * t - 1], t, C),
+            _by_offset(lc.row_stage_shoup[:, t - 1:2 * t - 1], t, C))
+           for t in (C >> i for i in range(1, C.bit_length()))]
+    A = _row_butterflies(x.reshape(*lead, R, C), col, lc.q)
+    A = mm.mulmod_shoup_lazy(_bitrev_rows(A), lc.twiddle, lc.twiddle_shoup, q3)
+    A = _row_butterflies(jnp.swapaxes(A, -1, -2), row, lc.q)
+    return _bitrev_rows(mm.reduce_once(A, q3)).reshape(*lead, R * C)
+
+
+def intt_lanes(x, lc: LaneConsts):
+    """Inverse of :func:`ntt_lanes`; equal to :func:`intt`.
+
+    Accepts lazy inputs (any values < 2q); output fully reduced in [0, q).
+    The mirror of the forward: row permutation and DIT inverse row DFT on
+    (C, R), one transpose, the inverse twiddle, row permutation and fused
+    GS inverse column NTT, then the C⁻¹·R⁻¹ scaling.
+    """
+    R, C = lc.twiddle.shape[-2:]
+    lead = x.shape[:-1]
+    q3 = lc.q[..., None]
+    row = [(m, False, _by_offset(lc.row_stage_inv[:, m - 1:2 * m - 1], m, C),
+            _by_offset(lc.row_stage_inv_shoup[:, m - 1:2 * m - 1], m, C))
+           for m in (1 << i for i in range(C.bit_length() - 1))]
+    col = [(R // (2 * h), True, _by_block(lc.col_psi_inv_rev[:, h:2 * h], h, R),
+            _by_block(lc.col_psi_inv_rev_shoup[:, h:2 * h], h, R))
+           for h in (R >> i for i in range(1, R.bit_length()))]
+    B = _row_butterflies(_bitrev_rows(x.reshape(*lead, C, R)), row, lc.q)
+    B = mm.mulmod_shoup_lazy(jnp.swapaxes(B, -1, -2), lc.twiddle_inv,
+                             lc.twiddle_inv_shoup, q3)
+    B = _row_butterflies(_bitrev_rows(B), col, lc.q)
+    B = mm.mulmod_shoup_lazy(B, lc.c_inv[..., None], lc.c_inv_shoup[..., None],
+                             q3)
+    B = mm.mulmod_shoup(B, lc.r_inv[..., None], lc.r_inv_shoup[..., None], q3)
+    return B.reshape(*lead, R * C)
 
 
 # ----------------------------------------------------------------------------

@@ -30,18 +30,35 @@ from . import trace
 COEFF = "coeff"
 NTT = "ntt"
 
+
+def ntt(x, lc: nttm.LaneConsts):
+    """The production forward NTT, :func:`~repro.core.ntt.ntt_lanes`."""
+    return nttm.ntt_lanes(x, lc)
+
+
+def intt(x, lc: nttm.LaneConsts):
+    """The production inverse NTT, :func:`~repro.core.ntt.intt_lanes`."""
+    return nttm.intt_lanes(x, lc)
+
+
 # Every ring op is ONE compiled program per (op, shape).  Dispatched op by op,
 # the u32 chains behind them (≈50 element-wise primitives per mulmod, ≈35 per
-# NTT butterfly stage) would each compile and launch on their own.
-_ntt, _intt = jax.jit(nttm.ntt), jax.jit(nttm.intt)
+# NTT butterfly stage) would each compile and launch on their own.  The NTT
+# programs keep the names ``jit_ntt``/``jit_intt`` (the wrappers above).
+_ntt, _intt = jax.jit(ntt), jax.jit(intt)
 _addmod, _submod = jax.jit(mm.addmod), jax.jit(mm.submod)
 _negmod, _mulmod = jax.jit(mm.negmod), jax.jit(mm.mulmod)
 mulmod_shoup = jax.jit(mm.mulmod_shoup)
 
 
-def consts(basis: tuple[int, ...], N: int) -> nttm.NttConsts:
-    """Per-limb NTT constants, staged to the device once per (basis, N)."""
-    return const_cache.device_ntt_consts(tuple(basis), N)
+def consts(basis: tuple[int, ...], N: int) -> const_cache.LimbConsts:
+    """Per-limb scalar constants, staged to the device once per (basis, N)."""
+    return const_cache.device_limb_consts(tuple(basis), N)
+
+
+def transform_consts(basis: tuple[int, ...], N: int) -> nttm.LaneConsts:
+    """The tables of :func:`ntt`/:func:`intt` for (basis, N), staged once."""
+    return const_cache.device_lane_consts(tuple(basis), N)
 
 
 @functools.partial(
@@ -64,7 +81,7 @@ class RnsPoly:
     def ell(self) -> int:
         return len(self.basis)
 
-    def c(self) -> nttm.NttConsts:
+    def c(self) -> const_cache.LimbConsts:
         return consts(self.basis, self.N)
 
     # -- domain conversion ---------------------------------------------------
@@ -78,7 +95,8 @@ class RnsPoly:
             return RnsPoly(dist.sharded_ntt(ctx, self.data, self.basis, True),
                            self.basis, NTT)
         with spans.span("ntt"):
-            return RnsPoly(_ntt(self.data, self.c()), self.basis, NTT)
+            return RnsPoly(_ntt(self.data, transform_consts(self.basis, self.N)),
+                           self.basis, NTT)
 
     def to_coeff(self) -> "RnsPoly":
         if self.domain == COEFF:
@@ -90,7 +108,8 @@ class RnsPoly:
             return RnsPoly(dist.sharded_ntt(ctx, self.data, self.basis, False),
                            self.basis, COEFF)
         with spans.span("intt"):
-            return RnsPoly(_intt(self.data, self.c()), self.basis, COEFF)
+            return RnsPoly(_intt(self.data, transform_consts(self.basis, self.N)),
+                           self.basis, COEFF)
 
     # -- ring ops (domain-agnostic element-wise; mul requires NTT) -----------
     def _check_aligned(self, o: "RnsPoly", op: str) -> None:

@@ -175,7 +175,6 @@ def four_step_tables(q: int, N: int, R: int) -> FourStepTables:
     C = N // R
     base = prime_tables(q, N)
     psi = base.psi
-    psi_inv = pow(psi, q - 2, q)
 
     # column-phase negacyclic tables for length R with psi_R = psi^C
     psi_R = pow(psi, C, q)
@@ -193,22 +192,21 @@ def four_step_tables(q: int, N: int, R: int) -> FourStepTables:
         qinv_neg=base.qinv_neg, r2=base.r2, mu_hi=base.mu_hi, mu_lo=base.mu_lo,
     )
 
-    # inter-step twiddles T[k1, n2] = psi^{(2 k1 + 1) n2}
-    tw = np.zeros((R, C), dtype=np.uint32)
-    tw_s = np.zeros((R, C), dtype=np.uint32)
-    tw_i = np.zeros((R, C), dtype=np.uint32)
-    tw_is = np.zeros((R, C), dtype=np.uint32)
-    for k1 in range(R):
-        base_w = pow(psi, 2 * k1 + 1, q)
-        base_wi = pow(psi_inv, 2 * k1 + 1, q)
-        w, wi = 1, 1
-        for n2 in range(C):
-            tw[k1, n2] = w
-            tw_s[k1, n2] = shoup(w, q)
-            tw_i[k1, n2] = wi
-            tw_is[k1, n2] = shoup(wi, q)
-            w = w * base_w % q
-            wi = wi * base_wi % q
+    # inter-step twiddles T[k1, n2] = psi^{(2 k1 + 1) n2}, read from a table
+    # of psi^e, e < 2N (psi^{2N} = 1); products of two residues < 2**30 and
+    # the Shoup numerators w·2**32 fit in u64
+    qq = np.uint64(q)
+    pw = np.ones(2 * N, dtype=np.uint64)
+    step, n = np.uint64(psi), 1
+    while n < 2 * N:
+        pw[n:2 * n] = pw[:n] * step % qq
+        step = step * step % qq
+        n *= 2
+    e = (2 * np.arange(R)[:, None] + 1) * np.arange(C)[None, :] % (2 * N)
+    w64, wi64 = pw[e], pw[(2 * N - e) % (2 * N)]
+    tw, tw_i = w64.astype(np.uint32), wi64.astype(np.uint32)
+    tw_s = ((w64 << np.uint64(WORD_BITS)) // qq).astype(np.uint32)
+    tw_is = ((wi64 << np.uint64(WORD_BITS)) // qq).astype(np.uint32)
 
     # row-phase cyclic powers: omega_C = psi^{2R}
     omega = pow(psi, 2 * R, q)

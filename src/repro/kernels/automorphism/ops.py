@@ -44,12 +44,12 @@ def auto_ks(exts, evk_a, evk_b, N: int, gs: tuple, basis: tuple[int, ...],
 
     ``basis`` is the extended basis Q_ℓ ∪ P of the hoisted digits; all limb
     constants (q, Montgomery, Barrett) come device-resident from
-    :func:`repro.core.const_cache.device_ntt_consts`.
+    :func:`repro.core.const_cache.device_limb_consts`.
     """
     if limbs_per_block is None:
         limbs_per_block = autotune.best_config(
             "auto_ks", N, exts.shape[-2])["limbs_per_block"]
-    c = const_cache.device_ntt_consts(tuple(basis), N)
+    c = const_cache.device_limb_consts(tuple(basis), N)
     perms = const_cache.device_galois_perm_stack(N, tuple(gs))
     interp = config.resolve_interpret(interpret)
     config.count_launch("auto_ks", interpret=interp)

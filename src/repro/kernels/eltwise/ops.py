@@ -1,7 +1,7 @@
 """Public wrappers for the EFU kernel.
 
 Per-limb constants come device-resident from
-:func:`repro.core.const_cache.device_ntt_consts` (staged once per (basis, N) —
+:func:`repro.core.const_cache.device_limb_consts` (staged once per (basis, N) —
 no per-call uploads), the execution mode resolves through
 :mod:`repro.kernels.config`, and unpinned launch knobs (``tile``,
 ``limbs_per_block``) resolve through the autotuned config cache
@@ -26,7 +26,7 @@ def eltwise(op: str, basis: tuple[int, ...], *arrays,
             tile = cfg.get("tile")
         if limbs_per_block is None:
             limbs_per_block = cfg.get("limbs_per_block")
-    c = const_cache.device_ntt_consts(tuple(basis), N)
+    c = const_cache.device_limb_consts(tuple(basis), N)
     interp = config.resolve_interpret(interpret)
     config.count_launch("eltwise", interpret=interp)
     return eltwise_pallas(op, c.q, c.qinv_neg, c.r2, *arrays, tile=tile,

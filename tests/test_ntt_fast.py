@@ -3,6 +3,7 @@ transforms must match the naive big-int oracle AND the pre-overhaul eager
 path bit-for-bit, lazy-reduction intermediates must stay below 2q, and the
 batched Pallas grid must be invariant in ``limbs_per_block``."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -182,3 +183,138 @@ def test_stage_major_row_tables_cover_all_stages():
         np.testing.assert_array_equal(fc.row_stage_inv[:, m - 1:2 * m - 1],
                                       fc.row_pow_inv[:, ::stride][:, :m])
         m *= 2
+
+
+# ------------------------------------------- lane-dense production transform
+
+_NTT, _INTT = jax.jit(nttm.ntt), jax.jit(nttm.intt)
+
+
+def _lane_fns():
+    """Fresh jits of the lane-dense pair, so a patched stage grouping is
+    traced anew."""
+    return (jax.jit(lambda x, lc: nttm.ntt_lanes(x, lc)),
+            jax.jit(lambda x, lc: nttm.intt_lanes(x, lc)))
+
+
+def _lazy(y, basis, seed):
+    """y plus q on a random half of the entries: the lazy range below 2q."""
+    rng = np.random.default_rng(seed)
+    qs = np.array(basis, dtype=np.uint32).reshape(-1, 1)
+    return y + qs * rng.integers(0, 2, y.shape).astype(np.uint32)
+
+
+@pytest.mark.parametrize("N,split", [
+    (1 << 7, (8, 16)), (1 << 8, (2, 128)), (1 << 10, (8, 128)),
+    (1 << 12, (32, 128)), (1 << 15, (128, 256)), (1 << 16, (256, 256)),
+    (1 << 2, (2, 2)), (1 << 3, (2, 4)), (1 << 6, (8, 8))])
+def test_lane_split_rule(N, split):
+    """R ≥ 2 rows, C the power of two nearest √N from above, raised to 128
+    lanes where N ≥ 2⁸ allows it."""
+    assert nttm.lane_split(N) == split
+
+
+def test_lane_split_needs_two_rows_and_columns():
+    with pytest.raises(ValueError):
+        nttm.lane_split(2)
+
+
+@pytest.mark.parametrize("N", [1 << 8, 1 << 10, 1 << 12, 1 << 6, 1 << 7])
+@pytest.mark.parametrize("lead", [(), (2,), (2, 3)])
+def test_lanes_match_iterative_and_naive(N, lead):
+    """The rule's split: bit-equal to ``ntt``/``intt`` (lazy inverse inputs
+    included) and to ``naive_ntt`` (one limb row, N ≤ 2¹⁰), and a round
+    trip."""
+    basis = tuple(rns.gen_ntt_primes(2, N))
+    c = nttm.stacked_ntt_consts(basis, N)
+    R, _ = nttm.lane_split(N)
+    lc = nttm.lane_consts(nttm.stacked_four_step_consts(basis, N, R))
+    rng = np.random.default_rng(N + len(lead))
+    x = np.stack([rng.integers(0, q, (*lead, N)) for q in basis],
+                 axis=-2).astype(np.uint32)
+    fwd, inv = _lane_fns()
+    got = np.asarray(fwd(x, lc))
+    np.testing.assert_array_equal(got, np.asarray(_NTT(x, c)))
+    if N <= 1 << 10 and not lead:
+        np.testing.assert_array_equal(got[1], nttm.naive_ntt(x[1], basis[1], N))
+    lazy = _lazy(got, basis, N)
+    back = np.asarray(inv(lazy, lc))
+    np.testing.assert_array_equal(back, np.asarray(_INTT(lazy, c)))
+    np.testing.assert_array_equal(back, x)
+
+
+@pytest.mark.parametrize("group", [1, 2, 3, 4])
+@pytest.mark.parametrize("N", [1 << 10, 1 << 7])
+def test_lanes_every_split_and_stage_grouping(monkeypatch, N, group):
+    """Every split the rule picks among (C ≥ 128 where N ≥ 2⁸ allows it,
+    else any R, C ≥ 2) and every stage grouping, including last groups
+    shorter than the rest."""
+    monkeypatch.setattr(nttm, "STAGE_GROUP", group)
+    basis = tuple(rns.gen_ntt_primes(2, N))
+    c = nttm.stacked_ntt_consts(basis, N)
+    x = rand_limbs(basis, N, seed=N + group)
+    want = np.asarray(_NTT(x, c))
+    lazy = _lazy(want, basis, group)
+    want_inv = np.asarray(_INTT(lazy, c))
+    R = 2
+    while N // R >= (nttm.LANES if N >= 2 * nttm.LANES else 2):
+        lc = nttm.lane_consts(nttm.stacked_four_step_consts(basis, N, R))
+        fwd, inv = _lane_fns()
+        np.testing.assert_array_equal(np.asarray(fwd(x, lc)), want,
+                                      err_msg=f"R={R}")
+        np.testing.assert_array_equal(np.asarray(inv(lazy, lc)), want_inv,
+                                      err_msg=f"inv R={R}")
+        R *= 2
+
+
+def test_lanes_at_the_paper_degree():
+    """N = 2¹⁶, 2 limbs, the 256×256 split the rule picks: bit-equal to the
+    iterative pair, lazy inverse inputs included, and a round trip."""
+    N = 1 << 16
+    basis = tuple(rns.gen_ntt_primes(2, N))
+    c = const_cache.device_ntt_consts(basis, N)
+    lc = const_cache.device_lane_consts(basis, N)
+    assert lc.twiddle.shape == (2, 256, 256)
+    x = rand_limbs(basis, N, seed=16)
+    fwd, inv = _lane_fns()
+    got = np.asarray(fwd(x, lc))
+    np.testing.assert_array_equal(got, np.asarray(_NTT(x, c)))
+    lazy = _lazy(got, basis, 16)
+    back = np.asarray(inv(lazy, lc))
+    np.testing.assert_array_equal(back, np.asarray(_INTT(lazy, c)))
+    np.testing.assert_array_equal(back, x)
+
+
+def test_ring_ops_take_the_lane_path_by_shape():
+    """``RnsPoly.to_ntt``/``to_coeff`` run the lane-dense transform at every
+    N, below 2⁸ too, with the iterative pair's results."""
+    from repro.core import poly
+    assert poly._ntt.__name__ == "ntt" and poly._intt.__name__ == "intt"
+    for N in (1 << 7, 1 << 10):
+        basis = tuple(rns.gen_ntt_primes(3, N))
+        lc = poly.transform_consts(basis, N)
+        assert isinstance(lc, nttm.LaneConsts)
+        assert lc.twiddle.shape[1:] == nttm.lane_split(N)
+        x = rand_limbs(basis, N, seed=N)
+        p = poly.RnsPoly(jnp.asarray(x), basis, poly.COEFF).to_ntt()
+        c = nttm.stacked_ntt_consts(basis, N)
+        np.testing.assert_array_equal(np.asarray(p.data),
+                                      np.asarray(_NTT(x, c)))
+        np.testing.assert_array_equal(np.asarray(p.to_coeff().data), x)
+
+
+def test_ring_ops_stage_only_lane_tables_and_limb_scalars():
+    """A basis the ring ops meet stages the lane tables and (ℓ, 1) scalar
+    columns, never the iterative transform's (ℓ, N) ψ tables."""
+    from repro.core import poly
+    N = 1 << 9
+    basis = tuple(rns.gen_ntt_primes(5, N))[2:]
+    misses = const_cache.device_ntt_consts.cache_info().misses
+    x = poly.RnsPoly(jnp.asarray(rand_limbs(basis, N, seed=9)), basis,
+                     poly.COEFF).to_ntt()
+    y = ((x * x + x - x).mul_scalar(np.arange(1, 4)) + (-x)).to_coeff()
+    jax.block_until_ready(y.data)
+    assert const_cache.device_ntt_consts.cache_info().misses == misses
+    lc = x.c()
+    assert isinstance(lc, const_cache.LimbConsts)
+    assert all(f.shape == (3, 1) for f in lc)
